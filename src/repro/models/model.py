@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import blocks as B
+from repro.telemetry import trace as telemetry_trace
 from repro.models.common import (GemmPolicy, NATIVE_POLICY, apply_norm, dense,
                                  emb_init, he_init, init_norm, pad_vocab)
 
@@ -256,8 +257,10 @@ def forward_step(params, mcfg: ModelConfig, tokens, start, n_new, cache,
 
     new_cache = {}
     if n_groups:
-        x, new_cache["layers"] = jax.lax.scan(
-            group_fn, x, (params["layers"], cache["layers"]))
+        # The scanned body is traced once and runs n_groups times.
+        with telemetry_trace.repeated(n_groups):
+            x, new_cache["layers"] = jax.lax.scan(
+                group_fn, x, (params["layers"], cache["layers"]))
     if tail:
         new_cache["tail"] = []
         for j, kind in enumerate(tail):

@@ -23,6 +23,16 @@ Emulation specifics:
   ``guard_retries`` eager attempts is failed alone — the rest of the
   cohort never replays and never pays backoff.
 
+**Self-tracing.** Each ``step_once`` is a ``serve.step`` span
+(``jax.profiler.StepTraceAnnotation``) holding ``serve.*`` spans for its
+parts (plan, admit, tables, inputs, dispatch, sync, guard, replay,
+commit, retire, record), on the profiler's clock; they cost about a microsecond
+each when no profiler records. The jitted step's KV gather and scatter
+run under the ``serve/kv_gather`` / ``serve/kv_scatter`` named scopes.
+Per-execution counts (emulated sites by step shape, steps that
+compiled, GC pauses, queue wait) are in :meth:`utilization` whether
+telemetry is on or off (docs/observability.md).
+
 The legacy whole-batch :class:`LockstepEngine` (prefill the full batch,
 decode in lockstep) is kept for API back-compat; the continuous engine's
 ``wave_admission`` mode reproduces its schedule with the new step
@@ -31,7 +41,9 @@ functions and is the baseline the serve benchmark gates against.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import time
 
 import jax
@@ -46,6 +58,7 @@ from repro.models.common import GemmPolicy
 from repro.serving.kv_cache import SCRATCH_PAGE, PagedKVCache
 from repro.serving.queue import Request, RequestQueue, RequestState
 from repro.serving.scheduler import ScheduleConfig, Scheduler, StepPlan
+from repro.telemetry import trace as _trace
 
 _GUARD_FIELDS = ("calls", "trips", "escalations", "recoveries",
                  "native_fallbacks", "masked")
@@ -109,15 +122,26 @@ class ContinuousEngine:
         self.sched = Scheduler(cfg, self.kv, wave=wave_admission)
         self.queue: RequestQueue = self.sched.queue
 
+        _trace.install_host_hooks()
+        # Profiles of the steps are read through their scopes' op names.
+        _trace.keep_op_names_in_compile_cache()
+        self._host0 = _trace.HOST.snapshot()
         self._step_fns = {c: self._make_step(c) for c in {1, chunk}}
+        # chunk -> {emugemm tag: sites per execution}, filled when the
+        # jitted step of that shape is traced.
+        self._sites: dict[int, dict[str, int]] = {}
+        self._site_executions: dict[int, dict[str, int]] = {}
+        self._step_compiles: dict[int, int] = {}
         # No donated buffers: a guard replay needs the pre-step pools
         # intact, and jit invalidates donated args even on failure.
-        self._jit_fns = {c: jax.jit(f) for c, f in self._step_fns.items()}
+        self._jit_fns = {c: jax.jit(self._collecting(c, f))
+                         for c, f in self._step_fns.items()}
         self.guard_retries = guard_retries
         self.guard_backoff = guard_backoff
         self.last_guard: dict[str, int] = {}
         self._results: dict[int, RequestResult] = {}
         self._step_idx = 0
+        self._calls = 0
         self._busy_steps = 0
         self._queue_nonempty_steps = 0
         self._t0 = time.monotonic()
@@ -135,14 +159,43 @@ class ContinuousEngine:
             self.mcfg.vocab
 
         def step(params, pools, tables, tokens, start, n_new):
-            views = kv.gather(pools, tables)
+            with jax.named_scope("serve/kv_gather"):
+                views = kv.gather(pools, tables)
             logits, views = M.forward_step(params, mcfg, tokens, start,
                                            n_new, views, policy)
-            pools = kv.scatter(pools, tables, views, start, n_new, c)
+            with jax.named_scope("serve/kv_scatter"):
+                pools = kv.scatter(pools, tables, views, start, n_new, c)
             tok = jnp.argmax(logits[:, :vocab], axis=-1).astype(jnp.int32)
             return tok, pools
 
         return step
+
+    def _collecting(self, c: int, step):
+        """``step``, noting in ``_sites[c]`` the emulated sites each
+        trace of it holds (the jitted step's body runs only when traced)."""
+        @functools.wraps(step)
+        def traced(*args):
+            with _trace.collect_sites() as sites:
+                out = step(*args)
+            self._sites[c] = dict(sites)
+            return out
+
+        return traced
+
+    def step_hlo_texts(self) -> dict[int, str]:
+        """chunk -> the compiled text of the jitted step of that shape,
+        lowered with an empty plan's arguments."""
+        b = self.sched.cfg.max_lanes
+        texts = {}
+        with self.mesh if self.mesh is not None else contextlib.nullcontext():
+            for c in sorted(self._jit_fns):
+                args = (self.params, self.pools,
+                        self.kv.tables_for([None] * b),
+                        jnp.zeros((b, c), jnp.int32),
+                        jnp.zeros((b,), jnp.int32),
+                        jnp.zeros((b,), jnp.int32))
+                texts[c] = self._jit_fns[c].lower(*args).compile().as_text()
+        return texts
 
     # ---- request intake -------------------------------------------------
 
@@ -165,23 +218,49 @@ class ContinuousEngine:
                 for f in _GUARD_FIELDS}
 
     def _execute(self, plan: StepPlan, tables) -> np.ndarray:
-        args = (self.params, self.pools, tables,
-                jnp.asarray(plan.tokens), jnp.asarray(plan.start),
-                jnp.asarray(plan.n_new))
+        with jax.profiler.TraceAnnotation("serve.inputs"):
+            args = (self.params, self.pools, tables,
+                    jnp.asarray(plan.tokens), jnp.asarray(plan.start),
+                    jnp.asarray(plan.n_new))
         before = guard.stats()
+        compiles = _trace.HOST.compiles
         try:
-            tok, pools = self._jit_fns[plan.chunk](*args)
-            sampled = np.asarray(tok)
+            with jax.profiler.TraceAnnotation("serve.dispatch"):
+                tok, pools = self._jit_fns[plan.chunk](*args)
+            self._count_execution(plan.chunk,
+                                  _trace.HOST.compiles != compiles)
+            with jax.profiler.TraceAnnotation("serve.sync"):
+                sampled = np.asarray(tok)
             self.pools = pools
-            delta = self._guard_delta(before)
+            with jax.profiler.TraceAnnotation("serve.guard"):
+                delta = self._guard_delta(before)
         except EmulationAccuracyError:
             # Strict trip surfaced at trace time (first call, constant
             # folding): fall straight to per-lane eager isolation.
             delta = {"trips": 1}
         self.last_guard = delta
         if delta.get("trips", 0) or delta.get("escalations", 0):
-            sampled = self._isolation_replay(plan, tables)
+            with jax.profiler.TraceAnnotation("serve.replay"):
+                sampled = self._isolation_replay(plan, tables)
         return sampled
+
+    def _count_execution(self, chunk: int, compiled: bool) -> None:
+        """One executed step of shape ``chunk``: its sites, and whether
+        its dispatch compiled."""
+        sites = self._sites.get(chunk, {})
+        counts = self._site_executions.setdefault(chunk, {})
+        for tag, n in sites.items():
+            counts[tag] = counts.get(tag, 0) + n
+        if compiled:
+            self._step_compiles[chunk] = self._step_compiles.get(chunk, 0) + 1
+        if not self._telemetry.enabled():
+            return
+        reg = self._telemetry.REGISTRY
+        rec = self._telemetry.record
+        for tag, n in sites.items():
+            reg.inc(rec.SERVE_SITE_EXECUTIONS, n, {"chunk": chunk, "tag": tag})
+        if compiled:
+            reg.inc(rec.SERVE_STEP_COMPILES, 1, {"chunk": chunk})
 
     def _isolation_replay(self, plan: StepPlan, tables) -> np.ndarray:
         """Re-run the tripped step one lane at a time, eagerly.
@@ -242,13 +321,17 @@ class ContinuousEngine:
 
     def _fail_lane(self, lane: int, state: RequestState) -> None:
         from repro.telemetry import record as _rec
-        state.status = "failed"
-        state.finished_at = self._clock()
-        self.kv.release(state.rid)
-        self.sched.lanes[lane] = None
-        self.sched.failed.append(state)
-        self._results[state.rid] = RequestResult.of(state)
-        _rec.record_event(_rec.SERVE_REQUESTS, {"outcome": "guard_failed"})
+        with jax.profiler.TraceAnnotation(
+                "serve.retire", rid=state.rid, status="failed",
+                tokens=len(state.generated)):
+            state.status = "failed"
+            state.finished_at = self._clock()
+            self.kv.release(state.rid)
+            self.sched.lanes[lane] = None
+            self.sched.failed.append(state)
+            self._results[state.rid] = RequestResult.of(state)
+            _rec.record_event(_rec.SERVE_REQUESTS,
+                              {"outcome": "guard_failed"})
 
     # ---- the serve loop -------------------------------------------------
 
@@ -257,22 +340,41 @@ class ContinuousEngine:
         plan, or None when nothing was runnable at ``now``."""
         if now is None:
             now = self._clock()
+        span = jax.profiler.StepTraceAnnotation("serve.step",
+                                                step_num=self._calls)
+        self._calls += 1
+        with span:
+            plan = self._step(now)
+            if plan is None:
+                span.set_metadata(chunk=0, lanes=0, tokens=0)
+            else:
+                span.set_metadata(chunk=plan.chunk,
+                                  lanes=int((plan.n_new > 0).sum()),
+                                  tokens=plan.scheduled_tokens)
+        return plan
+
+    def _step(self, now: float) -> StepPlan | None:
         evicted_before = self.sched.evictions
-        plan = self.sched.plan(now)
-        self._record_gauges(now)
+        with jax.profiler.TraceAnnotation("serve.plan"):
+            plan = self.sched.plan(now)
+        with jax.profiler.TraceAnnotation("serve.record"):
+            self._record_gauges(now)
         if plan is None:
             return None
-        tables = self.kv.tables_for(plan.rids)
+        with jax.profiler.TraceAnnotation("serve.tables"):
+            tables = self.kv.tables_for(plan.rids)
         t0 = time.perf_counter()
         sampled = self._execute(plan, tables)
         dt = time.perf_counter() - t0
-        retired = self.sched.commit(plan, sampled, self._clock())
-        self._record_step(plan, retired, dt,
-                          self.sched.evictions - evicted_before)
-        self._step_idx += 1
-        self._busy_steps += 1
-        if self.queue.depth(now) > 0:
-            self._queue_nonempty_steps += 1
+        with jax.profiler.TraceAnnotation("serve.commit"):
+            retired = self.sched.commit(plan, sampled, self._clock())
+        with jax.profiler.TraceAnnotation("serve.record"):
+            self._record_step(plan, retired, dt,
+                              self.sched.evictions - evicted_before)
+            self._step_idx += 1
+            self._busy_steps += 1
+            if self.queue.depth(now) > 0:
+                self._queue_nonempty_steps += 1
         return plan
 
     def run(self, requests=None, max_steps: int | None = None
@@ -345,13 +447,26 @@ class ContinuousEngine:
     # ---- introspection --------------------------------------------------
 
     def utilization(self) -> dict:
-        """Deterministic schedule-quality counters (see bench_serve)."""
+        """Schedule-quality counters (see bench_serve), and what the
+        engine's steps ran and waited on, telemetry on or off:
+        ``site_executions`` (chunk -> {emugemm tag: sites executed}),
+        ``step_compiles`` (chunk -> steps whose dispatch compiled),
+        process compiles and GC pauses since the engine was built, and
+        the queue wait of admissions."""
+        host = _trace.HOST.snapshot()
+        since = {k: host[k] - self._host0[k] for k in host}
         return {"steps": self._step_idx,
                 "busy_steps": self._busy_steps,
                 "queue_nonempty_steps": self._queue_nonempty_steps,
                 "evictions": self.sched.evictions,
                 "admissions": self.sched.admissions,
-                "kv": self.kv.stats()}
+                "kv": self.kv.stats(),
+                "site_executions": {c: dict(t) for c, t in
+                                    sorted(self._site_executions.items())},
+                "step_compiles": dict(sorted(self._step_compiles.items())),
+                **since,
+                "queue_wait_s": self.sched.queue_wait_s,
+                "queue_wait_max_s": self.sched.queue_wait_max_s}
 
 
 class LockstepEngine:

@@ -35,10 +35,13 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import numpy as np
 
 from repro.serving.kv_cache import PagedKVCache
 from repro.serving.queue import RequestQueue, RequestState
+from repro.telemetry import record as _rec
+from repro.telemetry import registry as _reg
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,6 +90,8 @@ class Scheduler:
         self.failed: list[RequestState] = []
         self.evictions = 0
         self.admissions = 0
+        self.queue_wait_s = 0.0       # summed over admissions
+        self.queue_wait_max_s = 0.0
 
     # ---- bookkeeping ----------------------------------------------------
 
@@ -148,14 +153,22 @@ class Scheduler:
                     or not self.kv.ensure(state.rid, first)):
                 self.queue.requeue(state)   # pages free up as lanes retire
                 break
-            lane = self.lanes.index(None)
-            self.lanes[lane] = state
-            state.status = "running"
-            state.prefill_len = len(state.effective_prompt)
-            state.fed = state.prompt_consumed
-            if state.admitted_at is None:
-                state.admitted_at = now
-            self.admissions += 1
+            wait = now - state.request.arrival
+            with jax.profiler.TraceAnnotation(
+                    "serve.admit", rid=state.rid, wait_ms=1e3 * wait):
+                lane = self.lanes.index(None)
+                self.lanes[lane] = state
+                state.status = "running"
+                state.prefill_len = len(state.effective_prompt)
+                state.fed = state.prompt_consumed
+                if state.admitted_at is None:
+                    state.admitted_at = now
+                self.admissions += 1
+                self.queue_wait_s += wait
+                self.queue_wait_max_s = max(self.queue_wait_max_s, wait)
+                if _reg.enabled():
+                    _reg.REGISTRY.observe(_rec.SERVE_QUEUE_WAIT_SECONDS,
+                                          wait)
             admitted += 1
         return admitted
 
@@ -252,11 +265,14 @@ class Scheduler:
                 if state.first_token_at is None:
                     state.first_token_at = now
                 if state.done:
-                    state.status = "done"
-                    state.finished_at = now
-                    self.kv.release(state.rid)
-                    self.lanes[lane] = None
-                    retired.append(state)
+                    with jax.profiler.TraceAnnotation(
+                            "serve.retire", rid=state.rid, status="done",
+                            tokens=len(state.generated)):
+                        state.status = "done"
+                        state.finished_at = now
+                        self.kv.release(state.rid)
+                        self.lanes[lane] = None
+                        retired.append(state)
         return retired
 
     # ---- invariants (exercised by tests) --------------------------------

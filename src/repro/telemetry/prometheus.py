@@ -20,7 +20,6 @@ _HELP = {
     "repro_emulated_calls_total": "Emulated GEMM executions by site/scheme/backend/impl.",
     "repro_emulated_traces_total": "Emulated GEMM trace/plan events.",
     "repro_modeled_hbm_bytes_total": "Modeled fused HBM bytes (paper Eq. 10/15/18) per execution.",
-    "repro_modeled_bytes_traced_total": "Modeled HBM bytes recorded at trace time, by emugemm tag.",
     "repro_modeled_collective_bytes_total": "Modeled collective bytes per device execution.",
     "repro_block_cache_total": "Block-selection cache lookups by result.",
     "repro_pad_total": "Traces that padded operands to meet backend alignment.",
@@ -32,6 +31,12 @@ _HELP = {
     "repro_shard_partition_total": "shard_map GEMM partition kinds chosen.",
     "repro_step_seconds": "Per-step wall-clock seconds.",
     "repro_step_tokens_per_s": "Most recent decode throughput.",
+    "repro_compiles_total": "Backend compiles (or compile-cache loads) in the process.",
+    "repro_compile_seconds": "Seconds per backend compile.",
+    "repro_gc_pause_seconds": "Python garbage-collection pauses by generation.",
+    "repro_serve_queue_wait_seconds": "Serve queue wait, arrival to admission.",
+    "repro_serve_site_executions_total": "Emulated GEMM sites executed by serve steps, by chunk and tag.",
+    "repro_serve_step_compiles_total": "Serve steps whose dispatch compiled, by chunk.",
 }
 
 

@@ -15,7 +15,7 @@ Two recording moments, matching how the stack executes:
 
 * **Trace time** (plan/route decisions, modeled bytes): recorded eagerly
   with a plain ``REGISTRY.inc`` while JAX traces — this is what compile-only
-  flows (``launch.dryrun``, ``utils.perf_probe``) observe.
+  flows (``launch.dryrun``) observe.
 * **Execution time** (call counts, modeled HBM/collective bytes per run):
   staged as a ``jax.debug.callback`` with the labels captured statically in
   the closure — the same pattern ``repro.guard`` uses.  ``debug.callback``
@@ -40,7 +40,6 @@ from repro.telemetry.registry import REGISTRY
 EMULATED_CALLS = "repro_emulated_calls_total"          # per execution
 EMULATED_TRACES = "repro_emulated_traces_total"        # per trace/plan
 MODELED_HBM_BYTES = "repro_modeled_hbm_bytes_total"    # per execution
-MODELED_BYTES_TRACED = "repro_modeled_bytes_traced_total"  # per trace, by tag
 BLOCK_CACHE = "repro_block_cache_total"                # hit/miss, per lookup
 PAD_EVENTS = "repro_pad_total"                         # per padded trace
 FALLBACK_EVENTS = "repro_fallback_total"               # per fallback, w/ reason
@@ -53,6 +52,9 @@ SHARD_PARTITION = "repro_shard_partition_total"        # partition kind chosen
 MODELED_COLLECTIVE_BYTES = "repro_modeled_collective_bytes_total"
 STEP_SECONDS = "repro_step_seconds"                    # histogram
 STEP_TOKENS_PER_S = "repro_step_tokens_per_s"          # gauge
+COMPILES = "repro_compiles_total"                      # backend compiles
+COMPILE_SECONDS = "repro_compile_seconds"              # histogram
+GC_PAUSE_SECONDS = "repro_gc_pause_seconds"            # histogram, generation
 
 # Continuous-batching serve engine (repro.serving; docs/serving.md).
 SERVE_QUEUE_DEPTH = "repro_serve_queue_depth"          # gauge, per step
@@ -64,6 +66,9 @@ SERVE_EVICTIONS = "repro_serve_evictions_total"        # counter
 SERVE_GUARD_TRIPS = "repro_serve_guard_trips_total"    # counter, per request
 SERVE_TTFT_SECONDS = "repro_serve_ttft_seconds"        # histogram
 SERVE_TPOT_SECONDS = "repro_serve_tpot_seconds"        # histogram
+SERVE_QUEUE_WAIT_SECONDS = "repro_serve_queue_wait_seconds"  # histogram
+SERVE_SITE_EXECUTIONS = "repro_serve_site_executions_total"  # chunk, tag
+SERVE_STEP_COMPILES = "repro_serve_step_compiles_total"      # chunk
 
 enabled = _reg.enabled
 
@@ -124,8 +129,7 @@ def gemm_tag(scheme: str, count: int, backend: str, impl: str) -> str:
     """Profiler scope tag: ``emugemm/<scheme>-<p|m><count>/<backend>/<impl>``.
 
     Scheme I counts mantissa slices (``p``); Scheme II counts moduli
-    (``m``).  Digits are meaningful here — perf_probe's tag normalizer
-    preserves them inside ``emugemm/`` scopes.
+    (``m``).
     """
     unit = "m" if scheme.startswith("ozaki2") else "p"
     return f"emugemm/{scheme}-{unit}{int(count)}/{backend}/{impl}"
@@ -204,16 +208,12 @@ def record_gemm(
     if not _reg.enabled():
         return
     labels = gemm_labels(scheme, backend, impl, m, k, n, mesh_shape, batch)
-    tag = gemm_tag(scheme, count, backend, impl)
     try:
         nbytes = modeled_gemm_bytes(scheme, count, m, k, n, out_bytes)
         nbytes *= batch or 1
     except Exception:
         nbytes = 0
     REGISTRY.inc(EMULATED_TRACES, 1, labels)
-    if nbytes:
-        REGISTRY.inc(MODELED_BYTES_TRACED, nbytes,
-                     {"tag": tag, "site": labels["site"]})
     import jax
 
     jax.debug.callback(functools.partial(_bump_gemm, labels, nbytes))

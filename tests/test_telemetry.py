@@ -265,18 +265,6 @@ def test_block_cache_counters():
     assert hits >= 1  # second call reuses the cached block choice
 
 
-def test_modeled_bytes_traced_by_tag():
-    telemetry.enable()
-    reg = telemetry.REGISTRY
-    tag = telemetry.gemm_tag("ozaki1", 4, "tpu", "pallas")
-    before = reg.total(tele_rec.MODELED_BYTES_TRACED, tag=tag)
-    cfg = EmulationConfig(scheme="ozaki1", p=4, backend="tpu")
-    dispatch.emulated_matmul(_rand((128, 128), 9), _rand((128, 128), 10),
-                             cfg=cfg)
-    got = reg.total(tele_rec.MODELED_BYTES_TRACED, tag=tag) - before
-    assert got == telemetry.modeled_gemm_bytes("ozaki1", 4, 128, 128, 128)
-
-
 def test_prepared_consume_counters():
     telemetry.enable()
     reg = telemetry.REGISTRY
