@@ -60,6 +60,30 @@ def _operand_stage_bytes(dim: int, bk: int, p: int, prologue: bool) -> int:
     return 2 * p * dim * bk  # double-buffered pre-interleaved int8 block
 
 
+def wide_candidates(dim: int) -> tuple[int, ...]:
+    """Block sizes above 128 for a dimension 128 divides and 256 does not.
+
+    Such a dimension takes no block from (512, 256): with 128 alone an
+    odd multiple of 128 (the 17 x 128 key view of an attention step) runs
+    one 128-wide grid step per lane tile.  Its candidates are then every
+    other multiple of 128 that divides it, largest first, the whole
+    dimension included; a dimension 256 divides gets none.
+    """
+    if dim % 128 or not dim % 256:
+        return ()
+    return tuple(b for b in range(dim, 128, -128) if not dim % b)
+
+
+def wide_dims(blocks: Blocks, m: int, n: int, k: int,
+              fixed_bk: int | None = None) -> tuple[str, ...]:
+    """The dims ('m', 'n', 'k') whose block came from ``wide_candidates``;
+    a pinned bK came from the pin."""
+    picked = [("m", blocks.bm, m), ("n", blocks.bn, n)]
+    if fixed_bk is None:
+        picked.append(("k", blocks.bk, k))
+    return tuple(name for name, b, dim in picked if b in wide_candidates(dim))
+
+
 def choose_blocks(m: int, n: int, k: int, p: int,
                   out_bytes: int = 4,
                   vmem_budget: int = VMEM_BUDGET,
@@ -81,6 +105,10 @@ def choose_blocks(m: int, n: int, k: int, p: int,
     the whole array dimension, and bK is the lane dimension of the A
     block: a K that 128 does not divide but the int8 sublane tile (32)
     does is taken whole.
+
+    A dimension 128 divides and 256 does not also tries its
+    ``wide_candidates``: the accumulators are int32, so any block is
+    bit-identical, and only the VMEM model and the preference decide.
     """
     best: tuple[tuple[int, int], Blocks] | None = None
     if fixed_bk is not None:
@@ -88,11 +116,12 @@ def choose_blocks(m: int, n: int, k: int, p: int,
     elif k % 128:
         bk_candidates = () if k % 32 else (k,)
     else:
-        bk_candidates = (512, 256, 128)
-    for bm in (512, 256, 128, 64, 32):
+        bk_candidates = wide_candidates(k) + (512, 256, 128)
+    bn_candidates = wide_candidates(n) + (512, 256, 128)
+    for bm in wide_candidates(m) + (512, 256, 128, 64, 32):
         if m % bm:
             continue
-        for bn in (512, 256, 128):
+        for bn in bn_candidates:
             if n % bn:
                 continue
             for bk in bk_candidates:
@@ -133,13 +162,28 @@ def carve_slices(r: jax.Array, p: int, beta: int):
 def balanced_mod(x: jax.Array, m: jax.Array) -> jax.Array:
     """int32 ``x`` mod ``m`` in balanced form [-(m//2), m - 1 - m//2].
 
+    The VPU has no integer divider: an int32 remainder lowers to a long
+    sequence, which took about nine tenths of a Scheme-II residue
+    GEMM's time on a TPU v5e.  The quotient is estimated in
+    float32 instead, and the remainder taken in int32, which is exact.
+    The first estimate errs by under 2^10 / m, so its remainder is
+    below 2^10 + 2m in size.  The second quotient of that remainder is
+    exact unless the remainder is a whole multiple of m, where it may
+    land one low and leave r = m, which the last select takes off.  An
+    int32 product that wraps wraps modulo 2^32, and the true remainder
+    fits, so the wrapped difference is exact.
+
     Written with int32 ``lax`` ops only: ``jnp.remainder`` and ``//`` on
     a kernel scalar promote through 64-bit integers when x64 is enabled,
     which the Mosaic lowering refuses.
     """
     half = jax.lax.div(m, jnp.int32(2))
-    r = jax.lax.rem(x + half, m)                # sign of the dividend
-    return jnp.where(r < 0, r + m, r) - half
+    inv = 1.0 / m.astype(jnp.float32)
+    r = x + half
+    for _ in range(2):
+        q = jnp.floor(r.astype(jnp.float32) * inv).astype(jnp.int32)
+        r = r - q * m
+    return jnp.where(r >= m, r - m, r) - half
 
 
 @functools.cache

@@ -57,7 +57,7 @@ from repro import telemetry
 from repro.core.precision import EmulationConfig
 from repro.kernels import backends
 from repro.kernels.backends.base import build_pallas_call  # noqa: F401
-from repro.kernels.common import Blocks
+from repro.kernels.common import Blocks, wide_dims
 from repro.telemetry import record as _tele
 
 # Historical MXU alignment; kept as the default for the padding helpers
@@ -149,6 +149,10 @@ def select_blocks(m: int, n: int, k: int, p: int, out_bytes: int = 4,
         blocks = bk_obj.choose_blocks(
             m, n, k, p, out_bytes=out_bytes, prologue_a=prologue_a,
             prologue_b=prologue_b, fixed_bk=fixed_bk)
+    if blocks is not None:
+        for dim in wide_dims(blocks, m, n, k, fixed_bk):
+            telemetry.record_event(_tele.BLOCK_WIDE,
+                                   {"backend": bucket, "dim": dim})
     cache.put(key, blocks)
     return blocks
 

@@ -22,6 +22,7 @@ _HELP = {
     "repro_modeled_hbm_bytes_total": "Modeled fused HBM bytes (paper Eq. 10/15/18) per execution.",
     "repro_modeled_collective_bytes_total": "Modeled collective bytes per device execution.",
     "repro_block_cache_total": "Block-selection cache lookups by result.",
+    "repro_block_wide_total": "Block selections that took a wide block, by dim.",
     "repro_pad_total": "Traces that padded operands to meet backend alignment.",
     "repro_fallback_total": "Backend/impl fallback events with reasons.",
     "repro_prepared_consume_total": "Prepared-operand consume routes (fused vs xla).",

@@ -41,6 +41,7 @@ EMULATED_CALLS = "repro_emulated_calls_total"          # per execution
 EMULATED_TRACES = "repro_emulated_traces_total"        # per trace/plan
 MODELED_HBM_BYTES = "repro_modeled_hbm_bytes_total"    # per execution
 BLOCK_CACHE = "repro_block_cache_total"                # hit/miss, per lookup
+BLOCK_WIDE = "repro_block_wide_total"                  # wide block, per miss
 PAD_EVENTS = "repro_pad_total"                         # per padded trace
 FALLBACK_EVENTS = "repro_fallback_total"               # per fallback, w/ reason
 BATCHED_LAUNCHES = "repro_emulated_batched_launches_total"  # per batched trace

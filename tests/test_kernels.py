@@ -10,11 +10,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro
+from repro import telemetry
 from repro.core import scheme1, scheme2
-from repro.core.precision import EmulationConfig, default_moduli
-from repro.kernels import matmul_int8, ops, ozaki1, ozaki2, ozaki3m
+from repro.core.precision import (DEFAULT_MODULI, EmulationConfig,
+                                  default_moduli)
+from repro.kernels import dispatch, matmul_int8, ops, ozaki1, ozaki2, ozaki3m
 from repro.kernels import ref as kref
-from repro.kernels.common import Blocks, choose_blocks
+from repro.kernels.common import Blocks, balanced_mod, choose_blocks
+from repro.telemetry import record as tele_rec
 
 SHAPES = [(128, 128, 128), (256, 512, 128), (384, 256, 256)]
 
@@ -122,7 +126,7 @@ def test_higher_p_forces_smaller_tiles():
     assert b1.bm * b1.bn >= b8.bm * b8.bn
 
 
-@pytest.mark.parametrize("k, bk", [(1024, 512), (384, 128), (352, 352),
+@pytest.mark.parametrize("k, bk", [(1024, 512), (384, 384), (352, 352),
                                    (96, 96), (100, None)])
 def test_block_k_is_lane_aligned_or_whole(k, bk):
     """bK is the lane dimension of the A block, which Mosaic takes as a
@@ -130,3 +134,115 @@ def test_block_k_is_lane_aligned_or_whole(k, bk):
     has no block."""
     blocks = choose_blocks(64, 128, k, 4, prologue_a=True, prologue_b=True)
     assert (blocks.bk if blocks else None) == bk
+
+
+_BOTH = dict(prologue_a=True, prologue_b=True)
+_SERVE = dict(out_bytes=2, prologue_a=True, prologue_b=True)
+
+# (m, n, k, p, choose_blocks keywords) -> the blocks chosen.
+BLOCK_CASES = {
+    # A dimension 128 divides and 256 does not takes a wider block.
+    "attn_qk_mixed_step": ((128, 2176, 128, 1, {}), Blocks(128, 2176, 128)),
+    "attn_qk_mixed_step_p6": ((128, 2176, 128, 6, dict(out_bytes=2)),
+                              Blocks(128, 2176, 128)),
+    "attn_av_mixed_step": ((128, 128, 2176, 4, _SERVE),
+                           Blocks(128, 128, 2176)),
+    "logits_prepared_50304": ((512, 50304, 2048, 4, dict(prologue_a=True)),
+                              Blocks(512, 384, 512)),
+    # Dimensions 256 divides keep the (512, 256, 128) candidates.
+    "sgemm_square_16k": ((16384, 16384, 16384, 4, _BOTH),
+                         Blocks(512, 512, 256)),
+    "sgemm_skinny_m256": ((256, 19200, 7168, 4, _BOTH),
+                          Blocks(256, 256, 512)),
+    "olmo_qkv_prepared": ((512, 6144, 2048, 4, dict(prologue_a=True)),
+                          Blocks(512, 512, 512)),
+    "olmo_up_prepared": ((512, 16384, 2048, 4, dict(prologue_a=True)),
+                         Blocks(512, 512, 512)),
+    "olmo_down_prepared": ((512, 2048, 8192, 4, dict(prologue_a=True)),
+                           Blocks(512, 512, 512)),
+    "olmo_logits_prepared": ((512, 50688, 2048, 4, dict(prologue_a=True)),
+                             Blocks(512, 512, 512)),
+    "olmo_out_mixed_step": ((1024, 2048, 2048, 4, _SERVE),
+                            Blocks(512, 512, 256)),
+    "olmo_up_mixed_step": ((1024, 8192, 2048, 4, _SERVE),
+                           Blocks(512, 512, 256)),
+    "olmo_down_mixed_step": ((1024, 2048, 8192, 4, _SERVE),
+                             Blocks(512, 512, 256)),
+    # A wide block the VMEM model refuses falls back to 128.
+    "vmem_refuses_257x128_p8": ((128, 257 * 128, 128, 8, {}),
+                                Blocks(128, 128, 128)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BLOCK_CASES))
+def test_block_choice(case):
+    (m, n, k, p, kw), want = BLOCK_CASES[case]
+    assert choose_blocks(m, n, k, p, **kw) == want
+
+
+def test_balanced_mod_exact_over_int32(rng):
+    """The float32-quotient remainder equals the exact balanced residue
+    for every default modulus (and small ones) across the int32 range."""
+    top = 2 ** 31 - 1 - 128  # x + m // 2 stays in int32
+    x = np.concatenate([
+        rng.integers(-2 ** 31, top, 200_000, endpoint=True),
+        np.arange(-70_000, 70_000),
+        [-2 ** 31, top, 2 ** 24 - 1, 2 ** 24, 2 ** 24 + 1, -2 ** 24 - 1],
+    ]).astype(np.int32)
+    bmod = jax.jit(balanced_mod)
+    for m in (*DEFAULT_MODULI, 2, 3, 7):
+        half = m // 2
+        want = np.mod(x.astype(np.int64) + half, m) - half
+        got = np.asarray(bmod(jnp.asarray(x), jnp.int32(m)))
+        np.testing.assert_array_equal(got, want, err_msg=f"m={m}")
+
+
+def test_ozaki2_wide_bn_bit_identical(rng):
+    """attn_qk's residue GEMM at N = 17 x 128: one bn = 2,176 block gives
+    the 128-wide tiles' residues exactly (int32 accumulators)."""
+    moduli = default_moduli(6)
+    a_res = jnp.asarray(rng.integers(-127, 128, (6, 128, 128)), jnp.int8)
+    b_res = jnp.asarray(rng.integers(-127, 128, (6, 128, 2176)), jnp.int8)
+    wide = choose_blocks(128, 2176, 128, 1)
+    assert wide.bn == 2176
+    out = ozaki2.fused_residue_matmul(a_res, b_res, moduli, wide)
+    narrow = ozaki2.fused_residue_matmul(a_res, b_res, moduli,
+                                         Blocks(128, 128, 128))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(narrow))
+
+
+def test_ozaki1_prologue_wide_bk_bit_identical(make_matrix):
+    """attn_av's Scheme-I GEMM at K = 17 x 128: bk = 2,176 in one step
+    gives what seventeen 128-deep steps give, bit for bit."""
+    m, k, n, p = 128, 2176, 128, 4
+    a = jnp.asarray(make_matrix((m, k)))
+    b = jnp.asarray(make_matrix((k, n)))
+    beta = EmulationConfig(scheme="ozaki1", p=p).resolved_beta(k)
+    mu = scheme1._pow2_row_scale(a, axis=1)
+    nu = scheme1._pow2_row_scale(b, axis=0)
+    wide = choose_blocks(m, n, k, p, prologue_a=True, prologue_b=True)
+    assert wide.bk == k
+    out = ozaki1.fused_matmul_prologue(a, b, mu, nu, p, beta, wide)
+    narrow = ozaki1.fused_matmul_prologue(a, b, mu, nu, p, beta,
+                                          Blocks(128, 128, 128))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(narrow))
+
+
+@pytest.mark.parametrize("eq, spec, a_shape, b_shape", [
+    # one lane, two heads, 128 queries against a 2,176-key view
+    ("bqkgd,bjkd->bkgqj", "ozaki2-m6", (1, 128, 2, 1, 128), (1, 2176, 2, 128)),
+    ("bkgqj,bjkd->bkgqd", "ozaki1-p4", (1, 2, 1, 128, 2176), (1, 2176, 2, 128)),
+])
+def test_attention_einsum_wide_blocks_match_expansion(make_matrix, eq, spec,
+                                                      a_shape, b_shape):
+    """The attention sites at K = N = 2,176 through the fused kernels
+    (wide blocks) equal the XLA expansion of the same scheme."""
+    a = jnp.asarray(make_matrix(a_shape))
+    b = jnp.asarray(make_matrix(b_shape))
+    dispatch.block_cache_clear()
+    with telemetry.recording() as reg:
+        wide0 = reg.total(tele_rec.BLOCK_WIDE)
+        fused = repro.einsum(eq, a, b, precision=spec)
+        assert reg.total(tele_rec.BLOCK_WIDE) > wide0
+    xla = repro.einsum(eq, a, b, precision=spec + "+xla")
+    np.testing.assert_array_equal(np.asarray(fused), np.asarray(xla))

@@ -265,6 +265,27 @@ def test_block_cache_counters():
     assert hits >= 1  # second call reuses the cached block choice
 
 
+def test_block_wide_counter():
+    """One event per new shape whose block came from the wide candidates,
+    on the cache miss only; none for 256-multiple shapes, none when off."""
+    wide = {"n": (128, 2176, 128, 1), "k": (128, 128, 2176, 1)}
+    dispatch.block_cache_clear("tpu")
+    with telemetry.recording() as reg:
+        before = {d: reg.total(tele_rec.BLOCK_WIDE, backend="tpu", dim=d)
+                  for d in "mnk"}
+        for shape in (*wide.values(), *wide.values(), (512, 512, 512, 1)):
+            dispatch.select_blocks(*shape, backend="tpu")
+        fired = {d: reg.total(tele_rec.BLOCK_WIDE, backend="tpu", dim=d)
+                 - before[d] for d in "mnk"}
+    assert fired == {"m": 0, "n": 1, "k": 1}
+    telemetry.disable()
+    dispatch.block_cache_clear("tpu")
+    total = telemetry.REGISTRY.total(tele_rec.BLOCK_WIDE)
+    for shape in wide.values():
+        dispatch.select_blocks(*shape, backend="tpu")
+    assert telemetry.REGISTRY.total(tele_rec.BLOCK_WIDE) == total
+
+
 def test_prepared_consume_counters():
     telemetry.enable()
     reg = telemetry.REGISTRY

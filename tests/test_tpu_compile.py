@@ -80,6 +80,32 @@ def _kernel_case(name, one_chip):
         fn = lambda a, b, mu, nu: ozaki1.fused_matmul_prologue(  # noqa: E731
             a, b, mu, nu, P, safe_beta(k), blocks)
         args = [(m, k, f32), (k, n, f32), (m, 1, f32), (1, n, f32)]
+    elif name == "ozaki2_vmap_wide_n":
+        # olmo-1b-emu's attn_qk site in a mixed step: 8 lanes x 16 heads
+        # of 128 queries against the 2,176-key view, one bn = 2,176 block.
+        m, k, n = 128, 128, 2176
+        assert choose_blocks(m, n, k, 1).bn == n
+        fn = jax.vmap(lambda a, b: ozaki2.fused_residue_matmul(  # noqa: E731
+            a, b, MODULI[:6]))
+        args = [(128, 6, m, k, i8), (128, 6, k, n, i8)]
+    elif name == "ozaki1_prologue_wide_k":
+        # olmo-1b-emu's attn_av site in a mixed step: K is the 2,176-key
+        # view, taken as one bk = 2,176 block.
+        m, k, n = 128, 2176, 128
+        blocks = choose_blocks(m, n, k, P, prologue_a=True, prologue_b=True)
+        assert blocks.bk == k
+        fn = lambda a, b, mu, nu: ozaki1.fused_matmul_prologue(  # noqa: E731
+            a, b, mu, nu, P, safe_beta(k), blocks)
+        args = [(m, k, f32), (k, n, f32), (m, 1, f32), (1, n, f32)]
+    elif name == "ozaki1_mixed_wide_n":
+        # An unpadded OLMo vocabulary (50,304 = 393 x 128) as a prepared
+        # logits weight at its m_hint of 512 rows: bn = 384.
+        m, k, n = 512, 2048, 50304
+        blocks = choose_blocks(m, n, k, P, prologue_a=True)
+        assert blocks.bn == 384
+        fn = lambda a, b, mu, nu: ozaki1.fused_matmul_mixed(  # noqa: E731
+            a, b, mu, nu, P, safe_beta(k), blocks)
+        args = [(m, k, f32), (P * k, n, i8), (m, 1, f32), (1, n, f32)]
     elif name == "ozaki1_interleaved":
         blocks = choose_blocks(N, N, N, P)
         fn = lambda a, b, mu, nu: ozaki1.fused_matmul_interleaved(  # noqa: E731
@@ -103,7 +129,10 @@ def _kernel_case(name, one_chip):
 
 @pytest.mark.parametrize("name", ["ozaki1_prologue", "ozaki1_interleaved",
                                   "ozaki1_prologue_whole_k", "ozaki2",
-                                  "ozaki3m", "decompose_interleave"])
+                                  "ozaki3m", "decompose_interleave",
+                                  "ozaki2_vmap_wide_n",
+                                  "ozaki1_prologue_wide_k",
+                                  "ozaki1_mixed_wide_n"])
 def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache,
                                  monkeypatch):
     # The host is a CPU, so the kernels would interpret; steer them to
