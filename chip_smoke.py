@@ -206,8 +206,8 @@ def gemm_x64_phase(n2: int = S2_N, seed: int = 1) -> None:
     compiler refuses or aborts on c128 values), so ZGEMM operands cannot
     reach the chip as complex arrays."""
     import jax
-    from repro.core.precision import (EmulationConfig, default_moduli,
-                                      scheme2_budget)
+    from repro.core.precision import EmulationConfig, default_moduli
+    from repro.core.scheme2 import operand_budget
     from repro.telemetry import enable
     enable()
     rng = np.random.default_rng(seed)
@@ -218,9 +218,8 @@ def gemm_x64_phase(n2: int = S2_N, seed: int = 1) -> None:
         cfg = EmulationConfig(scheme="ozaki2", p=len(moduli), moduli=moduli,
                               impl="pallas")
         real_t = np.real(a).dtype
-        # the library's budget: the moduli's, capped by the mantissa
-        budget = min(scheme2_budget(moduli, n2, complex_guard=complex_3m),
-                     np.finfo(real_t).nmant + 1)
+        # the library's own budget: the moduli's, capped by the mantissa
+        budget = operand_budget(moduli, n2, real_t, complex_guard=complex_3m)
         ref = (a[rows].astype(np.clongdouble if complex_3m else np.longdouble)
                @ b.astype(np.clongdouble if complex_3m else np.longdouble))
         run_gemm(f"ozaki2-m{len(moduli)}-{name}-{n2}", a, b, cfg,

@@ -18,8 +18,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.precision import EmulationConfig, scheme2_budget
+from repro.core.precision import EmulationConfig
 from repro.core import scheme2
+from repro.telemetry.trace import CRT, ENCODE, stage_scope
 
 
 def _balanced(x_int32: jax.Array, m: int) -> jax.Array:
@@ -35,25 +36,23 @@ def matmul(a: jax.Array, b: jax.Array, cfg: EmulationConfig,
     moduli = cfg.resolved_moduli()
     k_dim = a.shape[-1]
     scheme2.check_exact_k(k_dim, moduli)
-    budget = scheme2_budget(moduli, k_dim, complex_guard=True)
-    real_t = jnp.real(a).dtype
-    mant = jnp.finfo(real_t).nmant + 1
-    budget = min(budget, mant)
+    with stage_scope(ENCODE):
+        budget = scheme2.operand_budget(moduli, k_dim, jnp.real(a).dtype,
+                                        complex_guard=True)
+        ar, ai = jnp.real(a), jnp.imag(a)
+        br, bi = jnp.real(b), jnp.imag(b)
+        # One power-of-two scale per row/col shared by re/im parts.
+        mu = scheme2._pow2_int_scale(jnp.maximum(jnp.abs(ar), jnp.abs(ai)),
+                                     axis=1, budget_bits=budget)
+        nu = scheme2._pow2_int_scale(jnp.maximum(jnp.abs(br), jnp.abs(bi)),
+                                     axis=0, budget_bits=budget)
+        ar_i, ai_i = jnp.trunc(ar * mu), jnp.trunc(ai * mu)
+        br_i, bi_i = jnp.trunc(br * nu), jnp.trunc(bi * nu)
 
-    ar, ai = jnp.real(a), jnp.imag(a)
-    br, bi = jnp.real(b), jnp.imag(b)
-    # One power-of-two scale per row/col shared by re/im parts.
-    mu = scheme2._pow2_int_scale(jnp.maximum(jnp.abs(ar), jnp.abs(ai)),
-                                 axis=1, budget_bits=budget)
-    nu = scheme2._pow2_int_scale(jnp.maximum(jnp.abs(br), jnp.abs(bi)),
-                                 axis=0, budget_bits=budget)
-    ar_i, ai_i = jnp.trunc(ar * mu), jnp.trunc(ai * mu)
-    br_i, bi_i = jnp.trunc(br * nu), jnp.trunc(bi * nu)
-
-    ar_res = scheme2.balanced_residues(ar_i, moduli)   # (p, M, K) int8
-    ai_res = scheme2.balanced_residues(ai_i, moduli)
-    br_res = scheme2.balanced_residues(br_i, moduli)
-    bi_res = scheme2.balanced_residues(bi_i, moduli)
+        ar_res = scheme2.balanced_residues(ar_i, moduli)   # (p, M, K) int8
+        ai_res = scheme2.balanced_residues(ai_i, moduli)
+        br_res = scheme2.balanced_residues(br_i, moduli)
+        bi_res = scheme2.balanced_residues(bi_i, moduli)
 
     c_re_res, c_im_res = [], []
     for l, m in enumerate(moduli):
@@ -72,10 +71,11 @@ def matmul(a: jax.Array, b: jax.Array, cfg: EmulationConfig,
         c_re_res.append(jnp.remainder(t1m - t2m, m).astype(jnp.int32))
         c_im_res.append(jnp.remainder(t3m - t1m - t2m, m).astype(jnp.int32))
 
-    c_re = scheme2.crt_reconstruct(jnp.stack(c_re_res), moduli, out_dtype)
-    c_im = scheme2.crt_reconstruct(jnp.stack(c_im_res), moduli, out_dtype)
-    inv = 1.0 / (mu.astype(out_dtype) * nu.astype(out_dtype))
-    return jax.lax.complex(c_re * inv, c_im * inv)
+    with stage_scope(CRT):
+        c_re = scheme2.crt_reconstruct(jnp.stack(c_re_res), moduli, out_dtype)
+        c_im = scheme2.crt_reconstruct(jnp.stack(c_im_res), moduli, out_dtype)
+        inv = 1.0 / (mu.astype(out_dtype) * nu.astype(out_dtype))
+        return jax.lax.complex(c_re * inv, c_im * inv)
 
 
 def gemm_count(cfg: EmulationConfig) -> int:

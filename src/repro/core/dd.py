@@ -51,11 +51,24 @@ def two_prod(a, b):
     return p, e
 
 
-def mul_scalar(hi, lo, c: float):
-    """(hi, lo) * c  for a dtype-exact scalar c (e.g. small moduli)."""
-    c = jnp.asarray(c, dtype=hi.dtype)
-    p1, p2 = two_prod(hi, c)
-    p2 = p2 + lo * c
+def mul_scalar(hi, lo, c: int):
+    """(hi, lo) * c  for a small integer constant c (e.g. a modulus).
+
+    hi * c is formed exactly as two products: Veltkamp's split by the
+    constant's own width w (factor 2^w + 1) leaves a head of t - w bits
+    and a tail of at most w, each of whose products with c is exact, and
+    a two-sum of those gives the same (p, e) pair as Dekker's product.
+    The split scales hi by 2^w + 1 only, not by 2^ceil(t/2) + 1: where
+    float64 has float32's exponent range (a TPU), a Horner value near
+    2^110 times 2^27 would overflow to inf and the error term to NaN.
+    """
+    c = int(c)
+    width = max(1, c.bit_length())
+    t = hi * float(2 ** width + 1)
+    head = t - (t - hi)
+    c_ = jnp.asarray(float(c), dtype=hi.dtype)
+    p1, p2 = two_sum(head * c_, (hi - head) * c_)
+    p2 = p2 + lo * c_
     return quick_two_sum(p1, p2)
 
 

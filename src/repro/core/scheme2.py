@@ -34,6 +34,14 @@ from repro.core.precision import (EmulationAccuracyError, EmulationConfig,
                                   scheme2_budget)
 from repro.core import dd
 from repro.core.scheme1 import exact_pow2, frexp_exponent
+from repro.telemetry import record as _rec
+from repro.telemetry.trace import CRT, ENCODE, stage_scope
+
+# Outputs reconstructed at once by ``crt_unscale``: larger products run
+# their CRT over blocks of rows, so the digit planes and the
+# double-double temporaries of one block, not of the whole (M, N)
+# result, are live at a time.
+CRT_BLOCK = 1 << 20
 
 
 def _pow2_int_scale(a: jax.Array, axis: int, budget_bits: int) -> jax.Array:
@@ -52,6 +60,20 @@ def _pow2_int_scale(a: jax.Array, axis: int, budget_bits: int) -> jax.Array:
     info = jnp.finfo(a.dtype)
     e = jnp.minimum(budget_bits - exp, info.maxexp - 1)
     return exact_pow2(e, a.dtype)
+
+
+def operand_budget(moduli, k_dim: int, dtype,
+                   complex_guard: bool = False) -> int:
+    """Bits each operand is integerized to: the moduli's CRT budget
+    (:func:`scheme2_budget`) capped by the operand's mantissa.  Recorded
+    as the ``repro_scheme2_budget_bits`` gauge (labels ``moduli``,
+    ``dtype``) when telemetry is on: 52 for float64 at 16 moduli and
+    K = 8192, 8 for bfloat16.  Call it where the plan is made on every
+    trace, not inside a jitted body whose trace may be cached."""
+    bits = min(scheme2_budget(moduli, k_dim, complex_guard=complex_guard),
+               jnp.finfo(dtype).nmant + 1)
+    _rec.record_scheme2_budget(len(moduli), dtype, bits)
+    return bits
 
 
 def integerize(a: jax.Array, axis: int, budget_bits: int):
@@ -182,9 +204,59 @@ def mixed_radix_to_dd(digits: list[jax.Array], moduli) -> tuple[jax.Array, jax.A
                               else jnp.float32)
     lo = jnp.zeros_like(hi)
     for i in range(p - 2, -1, -1):
-        hi, lo = dd.mul_scalar(hi, lo, float(moduli[i]))
+        hi, lo = dd.mul_scalar(hi, lo, moduli[i])
         hi, lo = dd.add_scalar_array(hi, lo, digits[i].astype(hi.dtype))
     return hi, lo
+
+
+def canonical_residues(res: jax.Array, moduli) -> jax.Array:
+    """Residues (p, M, N) in any form (balanced int8, or int32 sums) ->
+    canonical [0, m_l) int32, one broadcast remainder against the
+    constant moduli."""
+    mods = jnp.asarray(moduli, jnp.int32).reshape(-1, 1, 1)
+    return jnp.remainder(res.astype(jnp.int32), mods)
+
+
+def crt_block_rows(m: int, n: int) -> int:
+    """Rows of C that :func:`crt_unscale` reconstructs at once: all M
+    while M N <= CRT_BLOCK, else the largest divisor of M whose rows hold
+    at most CRT_BLOCK outputs (one row at the least)."""
+    if m * n <= CRT_BLOCK:
+        return m
+    cap = max(1, min(m, CRT_BLOCK // n))
+    return next(r for r in range(cap, 0, -1) if m % r == 0)
+
+
+def crt_unscale(res: jax.Array, moduli, out_dtype, mu: jax.Array,
+                nu: jax.Array, *, canonical: bool = True) -> jax.Array:
+    """C = CRT(res) / (mu nu): the residues (p, M, N) of C' = A'B' back to
+    the emulated product, as ``out_dtype``.
+
+    ``canonical=False`` takes residues in any other form (the Mosaic
+    kernels' balanced int8) and canonicalizes them first.  Above
+    CRT_BLOCK outputs the rows run in blocks of :func:`crt_block_rows`
+    (``lax.map``), which bounds the live temporaries; each block computes
+    exactly what the unblocked form computes for its rows.
+    """
+    moduli = tuple(int(m) for m in moduli)
+    _, m, n = res.shape
+    nu_t = nu.astype(out_dtype)
+
+    def rows(r, mu_r):
+        if not canonical:
+            r = canonical_residues(r, moduli)
+        c = crt_reconstruct(r, moduli, out_dtype)
+        return c / (mu_r.astype(out_dtype) * nu_t)
+
+    step = crt_block_rows(m, n)
+    if step == m:
+        return rows(res, mu)
+
+    def block(i):
+        lo = i * step
+        return rows(jax.lax.dynamic_slice_in_dim(res, lo, step, axis=1),
+                    jax.lax.dynamic_slice_in_dim(mu, lo, step, axis=0))
+    return jax.lax.map(block, jnp.arange(m // step)).reshape(m, n)
 
 
 def crt_reconstruct(residues: jax.Array, moduli, out_dtype) -> jax.Array:
@@ -207,18 +279,17 @@ def matmul(a: jax.Array, b: jax.Array, cfg: EmulationConfig,
     moduli = cfg.resolved_moduli()
     k_dim = a.shape[-1]
     check_exact_k(k_dim, moduli)
-    budget = scheme2_budget(moduli, k_dim)
-    # Operand mantissa limits the useful budget (fp32 in -> 24 bits).
-    mant = jnp.finfo(a.dtype).nmant + 1
-    budget = min(budget, mant)
-    a_int, mu = integerize(a, axis=1, budget_bits=budget)
-    b_int, nu = integerize(b, axis=0, budget_bits=budget)
-    a_res = balanced_residues(a_int, moduli)
-    b_res = balanced_residues(b_int, moduli)
+    with stage_scope(ENCODE):
+        # Operand mantissa limits the useful budget (fp32 in -> 24 bits).
+        budget = operand_budget(moduli, k_dim, a.dtype)
+        a_int, mu = integerize(a, axis=1, budget_bits=budget)
+        b_int, nu = integerize(b, axis=0, budget_bits=budget)
+        a_res = balanced_residues(a_int, moduli)
+        b_res = balanced_residues(b_int, moduli)
     acc = residue_gemms(a_res, b_res)          # (p, M, N) int32, balanced
-    c_res = modular_reduce(acc, moduli)        # [0, m_l)
-    c_int = crt_reconstruct(c_res, moduli, out_dtype)
-    return c_int / (mu.astype(out_dtype) * nu.astype(out_dtype))
+    with stage_scope(CRT):
+        c_res = modular_reduce(acc, moduli)    # [0, m_l)
+        return crt_unscale(c_res, moduli, out_dtype, mu, nu)
 
 
 def effective_bits(moduli, k_dim: int) -> int:

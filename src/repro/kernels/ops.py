@@ -32,9 +32,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import complex3m, scheme1, scheme2
-from repro.core.precision import EmulationConfig, scheme2_budget
+from repro.core.precision import EmulationConfig
 from repro.kernels import dispatch, ozaki1, ozaki2, ozaki3m
 from repro.kernels.matmul_int8 import int8_matmul  # noqa: F401  (re-export)
+from repro.telemetry import trace
 
 
 def _resolve(cfg, scheme: str, p: int) -> EmulationConfig:
@@ -111,34 +112,19 @@ def fused_scheme1_matmul(a: jax.Array, b: jax.Array,
                                  out_dtype=out_dtype, blocks=blocks)
 
 
-def _canonical_residues(res8: jax.Array, moduli) -> jax.Array:
-    """Balanced (p, M, N) int8 residues -> canonical [0, m_l) int32.
-
-    One fused broadcast remainder against the constant moduli array —
-    the per-modulus Python loop unrolled p ``remainder`` + ``stack`` ops
-    into the graph; this is a single elementwise op.
-    """
-    mods = jnp.asarray(moduli, jnp.int32).reshape(-1, 1, 1)
-    return jnp.remainder(res8.astype(jnp.int32), mods)
-
-
-@partial(jax.jit, static_argnames=("cfg", "out_dtype"))
+@partial(jax.jit, static_argnames=("cfg", "out_dtype", "budget"))
 def _fused_scheme2_matmul(a: jax.Array, b: jax.Array, cfg: EmulationConfig,
-                          out_dtype=jnp.float32) -> jax.Array:
-    m, k = a.shape
-    _, n = b.shape
+                          out_dtype, budget: int) -> jax.Array:
     moduli = cfg.resolved_moduli()
-    scheme2.check_exact_k(k, moduli)
-    budget = min(scheme2_budget(moduli, k), jnp.finfo(a.dtype).nmant + 1)
-    a_int, mu = scheme2.integerize(a, axis=1, budget_bits=budget)
-    b_int, nu = scheme2.integerize(b, axis=0, budget_bits=budget)
-    a_res = scheme2.balanced_residues(a_int, moduli)
-    b_res = scheme2.balanced_residues(b_int, moduli)
+    with trace.stage_scope(trace.ENCODE):
+        a_int, mu = scheme2.integerize(a, axis=1, budget_bits=budget)
+        b_int, nu = scheme2.integerize(b, axis=0, budget_bits=budget)
+        a_res = scheme2.balanced_residues(a_int, moduli)
+        b_res = scheme2.balanced_residues(b_int, moduli)
     c_res8 = ozaki2.fused_residue_matmul(a_res, b_res, moduli)
-    c_res = _canonical_residues(c_res8, moduli)
-    out_t = jnp.dtype(out_dtype).type
-    c_int = scheme2.crt_reconstruct(c_res, moduli, out_t)
-    return c_int / (mu.astype(out_t) * nu.astype(out_t))
+    with trace.stage_scope(trace.CRT):
+        return scheme2.crt_unscale(c_res8, moduli, jnp.dtype(out_dtype).type,
+                                   mu, nu, canonical=False)
 
 
 def fused_scheme2_matmul(a: jax.Array, b: jax.Array,
@@ -146,53 +132,56 @@ def fused_scheme2_matmul(a: jax.Array, b: jax.Array,
                          out_dtype=jnp.float32) -> jax.Array:
     """End-to-end EmuGEMM-II real GEMM (cfg via ``repro.resolve_config``,
     ozaki2 with the default 8-modulus set when nothing is configured)."""
-    return _fused_scheme2_matmul(a, b, _resolve(cfg, "ozaki2", 8),
-                                 out_dtype=out_dtype)
+    cfg = _resolve(cfg, "ozaki2", 8)
+    moduli = cfg.resolved_moduli()
+    scheme2.check_exact_k(a.shape[1], moduli)
+    # The budget is taken here, outside the jitted body, so that every
+    # plan records it, a cached trace included.
+    budget = scheme2.operand_budget(moduli, a.shape[1], a.dtype)
+    return _fused_scheme2_matmul(a, b, cfg, out_dtype=out_dtype,
+                                 budget=budget)
 
 
-@partial(jax.jit, static_argnames=("cfg", "out_dtype"))
+@partial(jax.jit, static_argnames=("cfg", "out_dtype", "budget"))
 def _fused_3m_matmul(a: jax.Array, b: jax.Array, cfg: EmulationConfig,
-                     out_dtype=None) -> jax.Array:
+                     out_dtype, budget: int) -> jax.Array:
     if out_dtype is None:
         out_dtype = jnp.float64 if a.dtype == jnp.complex128 else jnp.float32
     out_t = jnp.dtype(out_dtype).type
     moduli = cfg.resolved_moduli()
-    k = a.shape[-1]
-    scheme2.check_exact_k(k, moduli)
-    real_t = jnp.real(a).dtype
-    budget = min(scheme2_budget(moduli, k, complex_guard=True),
-                 jnp.finfo(real_t).nmant + 1)
-    ar, ai = jnp.real(a), jnp.imag(a)
-    br, bi = jnp.real(b), jnp.imag(b)
-    mu = scheme2._pow2_int_scale(jnp.maximum(jnp.abs(ar), jnp.abs(ai)),
-                                 axis=1, budget_bits=budget)
-    nu = scheme2._pow2_int_scale(jnp.maximum(jnp.abs(br), jnp.abs(bi)),
-                                 axis=0, budget_bits=budget)
-    ar_res = scheme2.balanced_residues(jnp.trunc(ar * mu), moduli)
-    ai_res = scheme2.balanced_residues(jnp.trunc(ai * mu), moduli)
-    br_res = scheme2.balanced_residues(jnp.trunc(br * nu), moduli)
-    bi_res = scheme2.balanced_residues(jnp.trunc(bi * nu), moduli)
+    with trace.stage_scope(trace.ENCODE):
+        ar, ai = jnp.real(a), jnp.imag(a)
+        br, bi = jnp.real(b), jnp.imag(b)
+        mu = scheme2._pow2_int_scale(jnp.maximum(jnp.abs(ar), jnp.abs(ai)),
+                                     axis=1, budget_bits=budget)
+        nu = scheme2._pow2_int_scale(jnp.maximum(jnp.abs(br), jnp.abs(bi)),
+                                     axis=0, budget_bits=budget)
+        ar_res = scheme2.balanced_residues(jnp.trunc(ar * mu), moduli)
+        ai_res = scheme2.balanced_residues(jnp.trunc(ai * mu), moduli)
+        br_res = scheme2.balanced_residues(jnp.trunc(br * nu), moduli)
+        bi_res = scheme2.balanced_residues(jnp.trunc(bi * nu), moduli)
 
-    def sum_res(x_res, y_res, mm):
-        return complex3m._balanced(
-            x_res.astype(jnp.int32) + y_res.astype(jnp.int32), mm)
+        def sum_res(x_res, y_res, mm):
+            return complex3m._balanced(
+                x_res.astype(jnp.int32) + y_res.astype(jnp.int32), mm)
 
-    a3 = jnp.stack([
-        jnp.stack([ar_res[l], ai_res[l],
-                   sum_res(ar_res[l], ai_res[l], int(mm))])
-        for l, mm in enumerate(moduli)])          # (p, 3, M, K)
-    b3 = jnp.stack([
-        jnp.stack([br_res[l], bi_res[l],
-                   sum_res(br_res[l], bi_res[l], int(mm))])
-        for l, mm in enumerate(moduli)])          # (p, 3, K, N)
+        a3 = jnp.stack([
+            jnp.stack([ar_res[l], ai_res[l],
+                       sum_res(ar_res[l], ai_res[l], int(mm))])
+            for l, mm in enumerate(moduli)])          # (p, 3, M, K)
+        b3 = jnp.stack([
+            jnp.stack([br_res[l], bi_res[l],
+                       sum_res(br_res[l], bi_res[l], int(mm))])
+            for l, mm in enumerate(moduli)])          # (p, 3, K, N)
 
     c_re8, c_im8 = ozaki3m.fused_3m_residue_matmul(a3, b3, moduli)
-    c_re = _canonical_residues(c_re8, moduli)
-    c_im = _canonical_residues(c_im8, moduli)
-    cr = scheme2.crt_reconstruct(c_re, moduli, out_t)
-    ci = scheme2.crt_reconstruct(c_im, moduli, out_t)
-    inv = 1.0 / (mu.astype(out_t) * nu.astype(out_t))
-    return jax.lax.complex(cr * inv, ci * inv)
+    with trace.stage_scope(trace.CRT):
+        c_re = scheme2.canonical_residues(c_re8, moduli)
+        c_im = scheme2.canonical_residues(c_im8, moduli)
+        cr = scheme2.crt_reconstruct(c_re, moduli, out_t)
+        ci = scheme2.crt_reconstruct(c_im, moduli, out_t)
+        inv = 1.0 / (mu.astype(out_t) * nu.astype(out_t))
+        return jax.lax.complex(cr * inv, ci * inv)
 
 
 def fused_3m_matmul(a: jax.Array, b: jax.Array,
@@ -200,8 +189,13 @@ def fused_3m_matmul(a: jax.Array, b: jax.Array,
                     out_dtype=None) -> jax.Array:
     """End-to-end EmuGEMM-II complex GEMM via fused 3M (cfg via
     ``repro.resolve_config``)."""
-    return _fused_3m_matmul(a, b, _resolve(cfg, "ozaki2", 8),
-                            out_dtype=out_dtype)
+    cfg = _resolve(cfg, "ozaki2", 8)
+    moduli = cfg.resolved_moduli()
+    k = a.shape[-1]
+    scheme2.check_exact_k(k, moduli)
+    budget = scheme2.operand_budget(moduli, k, jnp.real(a).dtype,
+                                    complex_guard=True)
+    return _fused_3m_matmul(a, b, cfg, out_dtype=out_dtype, budget=budget)
 
 
 def maybe_fused_matmul(a: jax.Array, b: jax.Array, cfg: EmulationConfig):

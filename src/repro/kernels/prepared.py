@@ -43,7 +43,7 @@ import jax.numpy as jnp
 
 from repro import telemetry
 from repro.core import scheme1
-from repro.core.precision import EmulationConfig, scheme2_budget
+from repro.core.precision import EmulationConfig
 from repro.kernels.common import Blocks
 from repro.telemetry import record as _tele
 
@@ -328,8 +328,7 @@ def _encode_residues(b: jax.Array, moduli, k_dim: int):
     from repro.kernels.backends import gpu as gpu_backend
 
     b_pad = _pad2(b, align=gpu_backend.ALIGN)
-    budget = min(scheme2_budget(moduli, k_dim),
-                 jnp.finfo(b.dtype).nmant + 1)
+    budget = scheme2.operand_budget(moduli, k_dim, b.dtype)
     nu = scheme2._pow2_int_scale(b_pad, axis=0, budget_bits=budget)
     res = scheme2.balanced_residues(jnp.trunc(b_pad * nu), moduli)
     return res, nu, budget

@@ -38,6 +38,7 @@ _HELP = {
     "repro_serve_queue_wait_seconds": "Serve queue wait, arrival to admission.",
     "repro_serve_site_executions_total": "Emulated GEMM sites executed by serve steps, by chunk and tag.",
     "repro_serve_step_compiles_total": "Serve steps whose dispatch compiled, by chunk.",
+    "repro_scheme2_budget_bits": "Operand budget (bits) of the last Scheme-II plan, by moduli and dtype.",
 }
 
 

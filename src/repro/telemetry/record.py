@@ -56,6 +56,7 @@ STEP_TOKENS_PER_S = "repro_step_tokens_per_s"          # gauge
 COMPILES = "repro_compiles_total"                      # backend compiles
 COMPILE_SECONDS = "repro_compile_seconds"              # histogram
 GC_PAUSE_SECONDS = "repro_gc_pause_seconds"            # histogram, generation
+SCHEME2_BUDGET_BITS = "repro_scheme2_budget_bits"      # gauge, moduli/dtype
 
 # Continuous-batching serve engine (repro.serving; docs/serving.md).
 SERVE_QUEUE_DEPTH = "repro_serve_queue_depth"          # gauge, per step
@@ -242,6 +243,19 @@ def record_collective(kind: str, mesh_shape: Any, nbytes_per_device: int) -> Non
     jax.debug.callback(
         functools.partial(_bump_collective, labels, int(nbytes_per_device))
     )
+
+
+def record_scheme2_budget(moduli: int, dtype: Any, bits: int) -> None:
+    """Trace-time gauge: the operand budget (bits each operand is
+    integerized to) a Scheme-II plan took, by residue count and operand
+    dtype.  Gated on :func:`enabled`."""
+    if not _reg.enabled():
+        return
+    import numpy as np
+
+    REGISTRY.set_gauge(SCHEME2_BUDGET_BITS, int(bits),
+                       {"moduli": int(moduli),
+                        "dtype": np.dtype(dtype).name})
 
 
 def record_event(name: str, labels: Mapping[str, Any] | None = None,

@@ -11,6 +11,12 @@ Scopes are pure trace metadata: they change no numerics and cost nothing at
 run time, so they are applied unconditionally (not gated on
 ``telemetry.enabled()``).
 
+Scheme-II lowerings also name their stages inside that scope with
+:func:`stage_scope`: ``encode`` (integerize and residues) and ``crt``
+(canonicalize, Garner, Horner, unscale), so an op's ``op_name`` reads
+``.../emugemm/ozaki2-m16/tpu/pallas/.../encode/...``.  The residue
+kernel between them keeps its own name (``emugemm2_p<n>``).
+
 Because the scope is entered while JAX traces, it also tells a caller which
 sites a program holds: :func:`collect_sites` counts the tags entered while
 it is open, and :func:`repeated` scales the sites traced once in a loop
@@ -63,6 +69,25 @@ def gemm_scope(scheme: str, count: int, backend: str,
     if col is not None:
         col.sites[tag] += col.repeat
     with jax.named_scope(tag):
+        yield
+
+
+# Stages of a Scheme-II lowering (:func:`stage_scope`).
+ENCODE = "encode"
+CRT = "crt"
+STAGES = (ENCODE, CRT)
+
+
+@contextlib.contextmanager
+def stage_scope(stage: str) -> Iterator[None]:
+    """``jax.named_scope`` for one stage of an emulated GEMM, entered
+    inside its :func:`gemm_scope`: one of :data:`STAGES`."""
+    import jax
+
+    if stage not in STAGES:
+        raise ValueError(f"unknown emulated-GEMM stage {stage!r}; "
+                         f"known: {STAGES}")
+    with jax.named_scope(stage):
         yield
 
 
