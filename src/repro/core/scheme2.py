@@ -14,11 +14,16 @@ MXU path (TPU has no unsigned-int8 matmul). Congruence mod m is preserved, so
 the CRT is unchanged; |r_bal| <= 128 keeps K <= (2^31 - 1) / 2^14 = 131071
 exact (``check_exact_k`` enforces the bound on every pipeline).
 
-CRT reconstruction uses Garner's mixed-radix algorithm: digits d_i < m_i are
-computed in exact int32 arithmetic (O(p^2) elementwise ops), then the
-mixed-radix polynomial x = d_1 + m_1 (d_2 + m_2 (...)) is evaluated in
-double-double (~106 mantissa bits) — enough to round a <=120-bit integer to
-FP64 — replacing the paper's multi-word-integer CRT kernel.
+CRT reconstruction uses Garner's mixed-radix algorithm: balanced digits
+|d_i| <= m_i/2 are computed in exact int32 arithmetic, then the mixed-radix
+polynomial x = d_0 + m_0 (d_1 + m_1 (...)) is evaluated in double-double
+(~106 mantissa bits) — enough to round a <=120-bit integer to FP64 —
+replacing the paper's multi-word-integer CRT kernel.  Garner's chain for
+digit i, t <- (t - d_j) inv(m_j, m_i) mod m_i for each j < i, is folded into
+one congruence d_i = r_i C[i,0] - sum_{j<i} d_j C[i,j] (mod m_i) with
+constant C[i,j] < m_i (``garner_folded``): O(p^2) int32 multiply-adds whose
+sum stays under 2^19 for int8 residues, and one float32-quotient remainder
+per digit (``kernels.common.garner_mod``) where the chain took i.
 """
 
 from __future__ import annotations
@@ -68,11 +73,15 @@ def operand_budget(moduli, k_dim: int, dtype,
     (:func:`scheme2_budget`) capped by the operand's mantissa.  Recorded
     as the ``repro_scheme2_budget_bits`` gauge (labels ``moduli``,
     ``dtype``) when telemetry is on: 52 for float64 at 16 moduli and
-    K = 8192, 8 for bfloat16.  Call it where the plan is made on every
-    trace, not inside a jitted body whose trace may be cached."""
+    K = 8192, 8 for bfloat16.  Beside it goes the
+    ``repro_scheme2_crt_remainders`` gauge (label ``moduli``), the
+    remainders per output the plan's CRT takes (:func:`crt_remainders`).
+    Call it where the plan is made on every trace, not inside a jitted
+    body whose trace may be cached."""
     bits = min(scheme2_budget(moduli, k_dim, complex_guard=complex_guard),
                jnp.finfo(dtype).nmant + 1)
     _rec.record_scheme2_budget(len(moduli), dtype, bits)
+    _rec.record_scheme2_crt_remainders(len(moduli), crt_remainders(moduli))
     return bits
 
 
@@ -165,10 +174,43 @@ def garner_constants(moduli: tuple[int, ...]):
     return inv
 
 
+@lru_cache(maxsize=None)
+def garner_folded(moduli: tuple[int, ...]) -> tuple[tuple[int, ...], ...]:
+    """Garner's chain folded per digit (python ints): row i holds
+    C[i,j] = prod_{k=j}^{i-1} inv(m_k, m_i) mod m_i for j < i, so that
+    d_i = r_i C[i,0] - sum_{j<i} d_j C[i,j] (mod m_i)."""
+    inv = garner_constants(moduli)
+    rows = []
+    for i, m in enumerate(moduli):
+        row, c = [], 1
+        for j in range(i - 1, -1, -1):
+            c = c * int(inv[i, j]) % m
+            row.append(c)
+        rows.append(tuple(reversed(row)))
+    return tuple(rows)
+
+
+def crt_remainders(moduli) -> int:
+    """Remainders per output that :func:`garner_digits` takes: one per
+    digit whose modulus is not a power of two (5 at the default 6
+    moduli, 15 at 16), where Garner's chain took p (p - 1) / 2."""
+    return sum(1 for m in moduli if int(m) & (int(m) - 1))
+
+
 def garner_digits(residues: jax.Array, moduli) -> list[jax.Array]:
-    """*Balanced* mixed-radix digits d_i in [-m_i/2, m_i/2] with
-    x = d_0 + m_0 (d_1 + m_1 (d_2 + ...)), all exact int32 arithmetic.
-    ``residues``: (p, M, N) int32 in [0, m_l).
+    """*Balanced* mixed-radix digits d_i in [-(m_i - 1 - m_i//2), m_i//2]
+    with x = d_0 + m_0 (d_1 + m_1 (d_2 + ...)), all exact int32 arithmetic.
+    ``residues``: (p, M, N) integers in any representative of their class
+    (canonical [0, m_l), the kernels' balanced int8, or int32 sums of
+    such) with |r_l| < 2^23.
+
+    Each digit is one congruence of Garner's chain folded
+    (:func:`garner_folded`): v_i = r_i C[i,0] - sum_{j<i} d_j C[i,j], with
+    C[i,j] < m_i <= 256 and |d_j| <= 128, so |v_i| < 2^19 for int8
+    residues (2^20 for canonical ones) and int32 holds it exactly; one
+    float32-quotient remainder (``kernels.common.garner_mod``; a mask
+    for a power of two) then takes v_i into Garner's balanced range.
+    The digits are those of the chain, digit for digit.
 
     Balanced digits make the mixed-radix value itself the *centered*
     representative in (-P/2, P/2]: a small |x| has (near-)zero high digits,
@@ -177,18 +219,19 @@ def garner_digits(residues: jax.Array, moduli) -> list[jax.Array]:
     cancellation of 'evaluate then subtract P' disappears. This is the TPU
     (no int128) analogue of the paper's multi-word CRT kernel.
     """
+    # Lazy: repro.kernels pulls in Pallas, which importing this module
+    # must not.
+    from repro.kernels.common import garner_mod
     moduli = tuple(int(m) for m in moduli)
-    inv = garner_constants(moduli)
-    p = len(moduli)
+    coef = garner_folded(moduli)
     digits: list[jax.Array] = []
-    for i in range(p):
-        t = residues[i]
+    for i, m in enumerate(moduli):
+        v = residues[i].astype(jnp.int32)
+        if i:
+            v = v * coef[i][0]
         for j in range(i):
-            # t = (t - d_j) * inv(m_j, m_i) mod m_i; digits are balanced
-            # (|d_j| <= 128) so |t - d_j| * inv < 2^17 — exact in int32.
-            t = jnp.remainder((t - digits[j]) * int(inv[i, j]), moduli[i])
-        half = moduli[i] // 2
-        digits.append(jnp.where(t > half, t - moduli[i], t))
+            v = v - digits[j] * coef[i][j]
+        digits.append(garner_mod(v, m))
     return digits
 
 
@@ -228,12 +271,12 @@ def crt_block_rows(m: int, n: int) -> int:
 
 
 def crt_unscale(res: jax.Array, moduli, out_dtype, mu: jax.Array,
-                nu: jax.Array, *, canonical: bool = True) -> jax.Array:
+                nu: jax.Array) -> jax.Array:
     """C = CRT(res) / (mu nu): the residues (p, M, N) of C' = A'B' back to
     the emulated product, as ``out_dtype``.
 
-    ``canonical=False`` takes residues in any other form (the Mosaic
-    kernels' balanced int8) and canonicalizes them first.  Above
+    The residues may be in any form :func:`garner_digits` takes (the
+    Mosaic kernels' balanced int8 go in as they are).  Above
     CRT_BLOCK outputs the rows run in blocks of :func:`crt_block_rows`
     (``lax.map``), which bounds the live temporaries; each block computes
     exactly what the unblocked form computes for its rows.
@@ -243,8 +286,6 @@ def crt_unscale(res: jax.Array, moduli, out_dtype, mu: jax.Array,
     nu_t = nu.astype(out_dtype)
 
     def rows(r, mu_r):
-        if not canonical:
-            r = canonical_residues(r, moduli)
         c = crt_reconstruct(r, moduli, out_dtype)
         return c / (mu_r.astype(out_dtype) * nu_t)
 
@@ -261,7 +302,8 @@ def crt_unscale(res: jax.Array, moduli, out_dtype, mu: jax.Array,
 
 def crt_reconstruct(residues: jax.Array, moduli, out_dtype) -> jax.Array:
     """Signed CRT via balanced Garner digits: returns the centered
-    representative in (-P/2, P/2] as ``out_dtype``.
+    representative in (-P/2, P/2] as ``out_dtype``.  ``residues`` in any
+    form :func:`garner_digits` takes.
 
     Exact provided 2 sum_h |a'_ih||b'_hj| < P (paper Eq. 8 condition).
     """

@@ -304,7 +304,7 @@ def _crt_epilogue(acc, moduli, out_dtype):
     The entire residue tail of the reference pipeline — ``modular_reduce``
     (Eq. 7), balanced Garner digits, double-double mixed-radix Horner —
     runs in registers.  All moduli and inverse-table constants enter as
-    exact Python ints (``garner_constants``), so there is no eager-exp2
+    exact Python ints (``garner_folded``), so there is no eager-exp2
     style constant hazard; every op is exact integer / IEEE arithmetic
     and therefore bit-identical to the full-array reference restricted
     to this tile.

@@ -36,6 +36,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,6 +160,17 @@ def carve_slices(r: jax.Array, p: int, beta: int):
         r = shifted - s                   # exact fractional remainder
 
 
+def _mod_from(x: jax.Array, m, low, inv) -> jax.Array:
+    """int32 ``x`` mod ``m`` into [-low, m - 1 - low], with the quotient
+    estimated as floor(r * ``inv``) in float32 (``inv`` is 1/m rounded to
+    float32); see :func:`balanced_mod`."""
+    r = x + low
+    for _ in range(2):
+        q = jnp.floor(r.astype(jnp.float32) * inv).astype(jnp.int32)
+        r = r - q * m
+    return jnp.where(r >= m, r - m, r) - low
+
+
 def balanced_mod(x: jax.Array, m: jax.Array) -> jax.Array:
     """int32 ``x`` mod ``m`` in balanced form [-(m//2), m - 1 - m//2].
 
@@ -177,13 +189,24 @@ def balanced_mod(x: jax.Array, m: jax.Array) -> jax.Array:
     a kernel scalar promote through 64-bit integers when x64 is enabled,
     which the Mosaic lowering refuses.
     """
-    half = jax.lax.div(m, jnp.int32(2))
-    inv = 1.0 / m.astype(jnp.float32)
-    r = x + half
-    for _ in range(2):
-        q = jnp.floor(r.astype(jnp.float32) * inv).astype(jnp.int32)
-        r = r - q * m
-    return jnp.where(r >= m, r - m, r) - half
+    return _mod_from(x, m, jax.lax.div(m, jnp.int32(2)),
+                     1.0 / m.astype(jnp.float32))
+
+
+def garner_mod(x: jax.Array, m: int) -> jax.Array:
+    """int32 ``x`` mod the constant ``m`` in Garner's balanced form
+    [-(m - 1 - m//2), m//2]: :func:`balanced_mod`'s range for an odd m,
+    and its mirror for an even one (m/2 stays +m/2).
+
+    A power of two takes the low bits of ``x`` (two's complement) and
+    one select; any other modulus one float32-quotient remainder, exact
+    for every int32 as :func:`balanced_mod` is.  The constants are
+    Python scalars, so nothing promotes to 64 bits under x64.
+    """
+    if not m & (m - 1):
+        t = x & (m - 1)
+        return jnp.where(t > m // 2, t - m, t)
+    return _mod_from(x, m, m - 1 - m // 2, np.float32(1) / np.float32(m))
 
 
 @functools.cache

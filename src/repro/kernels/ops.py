@@ -124,7 +124,7 @@ def _fused_scheme2_matmul(a: jax.Array, b: jax.Array, cfg: EmulationConfig,
     c_res8 = ozaki2.fused_residue_matmul(a_res, b_res, moduli)
     with trace.stage_scope(trace.CRT):
         return scheme2.crt_unscale(c_res8, moduli, jnp.dtype(out_dtype).type,
-                                   mu, nu, canonical=False)
+                                   mu, nu)
 
 
 def fused_scheme2_matmul(a: jax.Array, b: jax.Array,

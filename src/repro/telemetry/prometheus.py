@@ -39,6 +39,7 @@ _HELP = {
     "repro_serve_site_executions_total": "Emulated GEMM sites executed by serve steps, by chunk and tag.",
     "repro_serve_step_compiles_total": "Serve steps whose dispatch compiled, by chunk.",
     "repro_scheme2_budget_bits": "Operand budget (bits) of the last Scheme-II plan, by moduli and dtype.",
+    "repro_scheme2_crt_remainders": "Remainders per output of the last Scheme-II plan's Garner digits, by moduli.",
 }
 
 

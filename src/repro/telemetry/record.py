@@ -57,6 +57,7 @@ COMPILES = "repro_compiles_total"                      # backend compiles
 COMPILE_SECONDS = "repro_compile_seconds"              # histogram
 GC_PAUSE_SECONDS = "repro_gc_pause_seconds"            # histogram, generation
 SCHEME2_BUDGET_BITS = "repro_scheme2_budget_bits"      # gauge, moduli/dtype
+SCHEME2_CRT_REMAINDERS = "repro_scheme2_crt_remainders"  # gauge, moduli
 
 # Continuous-batching serve engine (repro.serving; docs/serving.md).
 SERVE_QUEUE_DEPTH = "repro_serve_queue_depth"          # gauge, per step
@@ -256,6 +257,15 @@ def record_scheme2_budget(moduli: int, dtype: Any, bits: int) -> None:
     REGISTRY.set_gauge(SCHEME2_BUDGET_BITS, int(bits),
                        {"moduli": int(moduli),
                         "dtype": np.dtype(dtype).name})
+
+
+def record_scheme2_crt_remainders(moduli: int, remainders: int) -> None:
+    """Trace-time gauge: the remainders per output a Scheme-II plan's
+    CRT takes, by residue count.  Gated on :func:`enabled`."""
+    if not _reg.enabled():
+        return
+    REGISTRY.set_gauge(SCHEME2_CRT_REMAINDERS, int(remainders),
+                       {"moduli": int(moduli)})
 
 
 def record_event(name: str, labels: Mapping[str, Any] | None = None,

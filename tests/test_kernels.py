@@ -17,7 +17,8 @@ from repro.core.precision import (DEFAULT_MODULI, EmulationConfig,
                                   default_moduli)
 from repro.kernels import dispatch, matmul_int8, ops, ozaki1, ozaki2, ozaki3m
 from repro.kernels import ref as kref
-from repro.kernels.common import Blocks, balanced_mod, choose_blocks
+from repro.kernels.common import (Blocks, balanced_mod, choose_blocks,
+                                  garner_mod)
 from repro.telemetry import record as tele_rec
 
 SHAPES = [(128, 128, 128), (256, 512, 128), (384, 256, 256)]
@@ -195,6 +196,29 @@ def test_balanced_mod_exact_over_int32(rng):
         want = np.mod(x.astype(np.int64) + half, m) - half
         got = np.asarray(bmod(jnp.asarray(x), jnp.int32(m)))
         np.testing.assert_array_equal(got, want, err_msg=f"m={m}")
+
+
+@pytest.mark.parametrize("moduli", [
+    DEFAULT_MODULI[1:],          # odd: balanced_mod's range
+    (256, 128, 16, 2),           # powers of two: the low bits
+    (254, 250, 6),               # even, not a power of two: the mirror
+])
+def test_garner_mod_exact_over_int32(rng, moduli):
+    """Garner's balanced residue, t > m // 2 -> t - m on the canonical
+    one, for a constant modulus across the int32 range, in int32."""
+    top = 2 ** 31 - 1 - 128
+    x = np.concatenate([
+        rng.integers(-2 ** 31 + 128, top, 200_000, endpoint=True),
+        np.arange(-70_000, 70_000),
+        [-2 ** 31 + 128, top, 2 ** 24 - 1, 2 ** 24, 2 ** 24 + 1],
+    ]).astype(np.int32)
+    for m in moduli:
+        t = np.mod(x.astype(np.int64), m)
+        want = np.where(t > m // 2, t - m, t)
+        got = jax.jit(lambda v: garner_mod(v, m))(jnp.asarray(x))
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got), want,
+                                      err_msg=f"m={m}")
 
 
 def test_ozaki2_wide_bn_bit_identical(rng):

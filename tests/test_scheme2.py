@@ -124,3 +124,137 @@ def test_budget_respects_crt_bound():
 
 def test_linear_gemm_count():
     assert EmulationConfig(scheme="ozaki2", p=15).gemm_count() == 15
+
+
+def _chain_digits(res, moduli):
+    """Garner's O(p^2) chain, as the CRT took it before the fold: canonical
+    residues, then t <- (t - d_j) inv(m_j, m_i) mod m_i for each j < i,
+    balanced by t > m_i // 2 -> t - m_i (numpy int64, test-only)."""
+    inv = scheme2.garner_constants(tuple(moduli))
+    res = [np.mod(np.asarray(r, np.int64), m) for r, m in zip(res, moduli)]
+    digits = []
+    for i, m in enumerate(moduli):
+        t = res[i]
+        for j in range(i):
+            t = np.mod((t - digits[j]) * int(inv[i, j]), m)
+        digits.append(np.where(t > m // 2, t - m, t))
+    return digits
+
+
+def _residue_forms(rng, moduli, form, shape=(48, 40)):
+    """Residues of random integers in the forms the CRT is handed: the
+    kernels' balanced int8 (with -128 planted at modulus 256), or int32
+    sums t3 - t1 - t2 of canonical residues, as complex3m combines its
+    three products."""
+    if form == "int8":
+        res = rng.integers(-128, 128, (len(moduli), *shape)).astype(np.int8)
+        for l, m in enumerate(moduli):
+            if m == 256:
+                res[l, :3] = -128
+        return res
+    t = [rng.integers(0, m, (3, *shape)) for m in moduli]
+    return np.stack([t3 - t1 - t2 for t1, t2, t3 in t]).astype(np.int32)
+
+
+def _assert_digits_equal(res, moduli):
+    got = scheme2.garner_digits(jnp.asarray(res), moduli)
+    want = _chain_digits(res, moduli)
+    assert len(got) == len(want)
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert g.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(g), w, err_msg=f"digit {i}")
+
+
+@pytest.mark.parametrize("form", ["int8", "int32-sums"])
+@pytest.mark.parametrize("p", [2, 3, 6, 8, 16])
+def test_folded_digits_equal_garners_chain(p, form):
+    moduli = default_moduli(p)
+    _assert_digits_equal(_residue_forms(np.random.default_rng(p), moduli,
+                                        form), moduli)
+
+
+@pytest.mark.parametrize("moduli", [
+    (255, 256),          # a power of two after the first
+    (255, 254, 253),     # an even modulus that is not a power of two
+    (7, 16, 9),          # small moduli: int8 residues span many periods
+])
+def test_folded_digits_with_an_even_modulus_after_the_first(moduli):
+    rng = np.random.default_rng(len(moduli))
+    for form in ("int8", "int32-sums"):
+        _assert_digits_equal(_residue_forms(rng, moduli, form), moduli)
+
+
+def test_garner_folded_table():
+    """Row i folds Garner's i steps: C[i,j] is the product of the inverses
+    of m_j .. m_{i-1} modulo m_i, below m_i (246 at most by default)."""
+    moduli = default_moduli(16)
+    table = scheme2.garner_folded(moduli)
+    for i, m in enumerate(moduli):
+        assert len(table[i]) == i
+        for j, c in enumerate(table[i]):
+            assert 0 < c < m
+            assert c * math.prod(moduli[j:i]) % m == 1
+    assert max(max(row) for row in table if row) == 246
+
+
+@pytest.mark.parametrize("p,dtype", [(6, "float32"), (6, "bfloat16"),
+                                     (16, "float64")])
+def test_crt_unscale_of_int8_residues_equals_canonical(p, dtype):
+    """The kernels' int8 residues go into the CRT as they are and give the
+    bits that their canonical form gives, and that Garner's chain gave."""
+    moduli = default_moduli(p)
+    rng = np.random.default_rng(p)
+    res8 = _residue_forms(rng, moduli, "int8")
+    canon = np.stack([np.mod(r.astype(np.int64), m)
+                      for r, m in zip(res8, moduli)]).astype(np.int32)
+    with jax.enable_x64(dtype == "float64"):
+        out_t = jnp.dtype(dtype).type
+        mu = jnp.asarray(2.0 ** rng.integers(-3, 4, (res8.shape[1], 1)),
+                         out_t)
+        nu = jnp.asarray(2.0 ** rng.integers(-3, 4, (1, res8.shape[2])),
+                         out_t)
+        crt = jax.jit(lambda r, u, v: scheme2.crt_unscale(r, moduli, out_t,
+                                                          u, v))
+        got = np.asarray(crt(jnp.asarray(res8), mu, nu))
+        want = np.asarray(crt(jnp.asarray(canon), mu, nu))
+        hi, lo = scheme2.mixed_radix_to_dd(
+            [jnp.asarray(d, jnp.int32) for d in _chain_digits(res8, moduli)],
+            moduli)
+        chain = np.asarray((hi.astype(out_t) + lo.astype(out_t)).astype(out_t)
+                           / (mu * nu))
+    assert got.dtype == jnp.dtype(dtype)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got, chain)
+
+
+def _count_primitives(jaxpr, name: str) -> int:
+    """Occurrences of primitive ``name`` in ``jaxpr``, nested jaxprs
+    (jit, cond, map bodies) included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    n += _count_primitives(inner, name)
+    return n
+
+
+@pytest.mark.parametrize("p,dtype", [(6, "float32"), (16, "float64")])
+def test_crt_takes_one_float32_quotient_remainder_per_digit(p, dtype):
+    """The jaxpr of the CRT holds no integer remainder (digit 0 may take
+    one) and two float32 quotients for each of the ``crt_remainders``
+    digits; nothing in Garner's digits widens to 64 bits under x64."""
+    moduli = default_moduli(p)
+    res = jax.ShapeDtypeStruct((p, 8, 128), jnp.int8)
+    with jax.enable_x64(True):
+        crt = jax.make_jaxpr(lambda r: scheme2.crt_reconstruct(
+            r, moduli, jnp.dtype(dtype).type))(res).jaxpr
+        digits = jax.make_jaxpr(lambda r: scheme2.garner_digits(
+            r, moduli))(res).jaxpr
+    assert _count_primitives(crt, "rem") <= 1
+    assert scheme2.crt_remainders(moduli) == p - 1
+    assert _count_primitives(crt, "floor") == 2 * (p - 1)
+    widths = {str(v.aval.dtype) for e in digits.eqns for v in e.outvars}
+    assert not [w for w in widths if "64" in w], widths

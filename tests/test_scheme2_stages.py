@@ -1,12 +1,13 @@
-"""Scheme II's stage scopes, its operand-budget gauge and its row-blocked
-CRT.
+"""Scheme II's stage scopes, its operand-budget and CRT-remainder gauges
+and its row-blocked CRT.
 
 Every real Scheme-II lowering (the fused kernel path, the XLA expansion
 and both 3M paths) names its ``encode`` and ``crt`` stages inside its
 ``emugemm/...`` scope, so a profiler trace can split its device time;
-each plan records the operand budget it took; and a product of more
-than ``scheme2.CRT_BLOCK`` outputs reconstructs its rows in blocks, with
-the same bits as the unblocked form.
+each plan records the operand budget it took and the remainders per
+output its CRT takes; and a product of more than ``scheme2.CRT_BLOCK``
+outputs reconstructs its rows in blocks, with the same bits as the
+unblocked form.
 """
 
 import dataclasses
@@ -114,6 +115,46 @@ def test_budget_gauge_is_silent_with_telemetry_off():
     assert budget_gauges() == {}
 
 
+def remainder_gauges() -> dict:
+    snap = telemetry.REGISTRY.snapshot()
+    return {g["labels"]["moduli"]: g["value"] for g in snap["gauges"]
+            if g["name"] == record.SCHEME2_CRT_REMAINDERS}
+
+
+@pytest.mark.parametrize("spec,dtype,shapes,count", [
+    # 15 remainders per output where Garner's chain took 120, and the
+    # residues' canonicalization 16 more.
+    ("ozaki2-m16", "float64", ((128, 256), (256, 128)), 15),
+    ("ozaki2-m6", "bfloat16", ((128, 128), (128, 2176)), 5),
+])
+def test_crt_remainders_gauge_records_the_plans_count(spec, dtype, shapes,
+                                                      count):
+    jax.clear_caches()
+    telemetry.REGISTRY.clear(record.SCHEME2_CRT_REMAINDERS)
+    with jax.enable_x64(True), telemetry.recording():
+        a = jax.ShapeDtypeStruct(shapes[0], jnp.dtype(dtype))
+        b = jax.ShapeDtypeStruct(shapes[1], jnp.dtype(dtype))
+        jax.jit(lambda x, y: repro.dot_general(
+            x, y, DIMS, precision=spec)).lower(a, b)
+    moduli = spec.rsplit("m", 1)[1]
+    assert remainder_gauges() == {moduli: count}
+    assert (f'repro_scheme2_crt_remainders{{moduli="{moduli}"}} {count}'
+            in telemetry.render_prometheus())
+    telemetry.REGISTRY.clear(record.SCHEME2_CRT_REMAINDERS)
+
+
+def test_crt_remainders_gauge_is_silent_with_telemetry_off():
+    jax.clear_caches()
+    telemetry.REGISTRY.clear(record.SCHEME2_CRT_REMAINDERS)
+    assert not telemetry.enabled()
+    with jax.enable_x64(True):
+        a = jax.ShapeDtypeStruct((128, 256), jnp.float64)
+        b = jax.ShapeDtypeStruct((256, 128), jnp.float64)
+        jax.jit(lambda x, y: repro.dot_general(
+            x, y, DIMS, precision="ozaki2-m16")).lower(a, b)
+    assert remainder_gauges() == {}
+
+
 @pytest.mark.parametrize("m,n,rows", [
     (8192, 8192, 128),     # the DGEMM cell: 64 blocks of 128 rows
     (1024, 1024, 1024),    # at CRT_BLOCK: one block
@@ -144,7 +185,7 @@ def test_blocked_crt_is_bit_identical(monkeypatch, dtype, canonical):
 
         def run():
             return np.asarray(jax.jit(lambda r, u, v: scheme2.crt_unscale(
-                r, moduli, dtype, u, v, canonical=canonical))(res, mu, nu))
+                r, moduli, dtype, u, v))(res, mu, nu))
 
         whole = run()
         monkeypatch.setattr(scheme2, "CRT_BLOCK", 24 * n)
